@@ -536,6 +536,9 @@ def _run_smoke_sharded(regime: str = "random") -> None:
 def main() -> None:
     import argparse
 
+    from repro.launch.device import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description="construction-path bench")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny workload end to end (CI); with --backend "

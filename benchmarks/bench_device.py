@@ -368,6 +368,9 @@ def run(smoke: bool = False, profile_dir: str | None = None) -> list[list]:
 def main() -> None:
     import argparse
 
+    from repro.launch.device import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description="device serving-path bench")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny workload: exercise every variant (CI)")

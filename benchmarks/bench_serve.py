@@ -361,6 +361,9 @@ def run(smoke: bool = False, rate: float = 0.0, deadline_ms: float = 0.0,
 
 
 def main() -> None:
+    from repro.launch.device import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description="serve-engine lifecycle bench")
     ap.add_argument("--smoke", action="store_true",
                     help="short fixed workload + p99-regression gate (CI)")

@@ -15,6 +15,9 @@ from . import bench_ablations, bench_build, bench_dc, bench_device, bench_query
 
 
 def main() -> None:
+    from repro.launch.device import use_compile_cache
+
+    use_compile_cache()
     t0 = time.time()
     print("name,us_per_call,derived")
     for mod, tag in [

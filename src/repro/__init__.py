@@ -1,8 +1,4 @@
 """repro — WoW (window-to-window RFANNS) reproduction on jax/Pallas.
 
-Importing the package installs small forward-compat shims for older jax
-runtimes (see ``repro._compat``); everything else lives in subpackages.
+Everything lives in subpackages.
 """
-from . import _compat as _jax_compat
-
-_jax_compat.install()

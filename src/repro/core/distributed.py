@@ -6,13 +6,11 @@ vectors) is replicated within each data group.  Each device runs the batched
 beam search on its query shard — no collectives on the hot path, linear
 scaling in devices.  Every piece of per-query hop state (result arrays and
 the visited filter — the [B, n/32] bitmap or the [B, v_words] hashed
-filter) is leading-dim-B, so the whole ``HopState`` shards over the data
-axis by propagation from the query sharding; at million-vector scale the
-hashed filter is the only option that keeps the replicated-per-device state
-O(batch) instead of O(batch * n).  For snapshots larger than one device,
-the ``model`` axis shards the *vector dimension* for the distance matmul
-(column-parallel with a ``psum`` of partial dot products) — exposed via
-``dim_sharded=True``.
+filter) is leading-dim-B, so each device holds only its shard's
+``HopState``; at million-vector scale the hashed filter is the only option
+that keeps the per-device state O(batch) instead of O(batch * n).  The
+search runs under ``shard_map`` (one hop loop per device, Pallas kernel
+included); the ``model`` axis, when larger than 1, holds further replicas.
 
 The sharded serving function runs the lock-step hop loop (``compact=None``
 — ragged-batch compaction is host-side scheduling and cannot live inside
@@ -70,6 +68,7 @@ from .device_search import (
     _pow2ceil,
     _prep_build_inputs,
     device_search,
+    to_device_index,
     visited_filter_bits,
     visited_filter_bits_from_hist,
 )
@@ -208,24 +207,7 @@ def make_serving_fn(
     else:
         bits0 = None  # bitmap mode: nothing to adapt
 
-    from .store import quantize_rows
-
-    vec_slab, vec_scales = quantize_rows(
-        np.asarray(snap.vectors, np.float32), vec_dtype
-    )
-    di = DeviceIndex(
-        vectors=jnp.asarray(vec_slab),
-        sq_norms=jnp.asarray(snap.sq_norms, jnp.float32),
-        attrs=jnp.asarray(snap.attrs, jnp.float32),
-        neighbors=jnp.asarray(snap.neighbors, jnp.int32),
-        uvals=jnp.asarray(snap.uvals, jnp.float32),
-        uval_rep=jnp.asarray(snap.uval_rep, jnp.int32),
-        scales=jnp.asarray(
-            vec_scales if vec_scales is not None else np.ones(1, np.float32),
-            jnp.float32,
-        ),
-    )
-    di = jax.device_put(di, rep)
+    di = jax.device_put(to_device_index(snap, vec_dtype=vec_dtype), rep)
 
     def _make_fn(bits):
         searcher = functools.partial(
@@ -240,6 +222,17 @@ def make_serving_fn(
             pipeline=pipeline,
             visited=visited,
             visited_bits=bits,
+        )
+        # the per-shard search runs under shard_map: each device drives its
+        # own hop loop (and the Pallas gather kernel, which GSPMD cannot
+        # partition) over its query slice, stopping when its own queries
+        # terminate; per-query trajectories are row-independent, so the
+        # results equal the one-device search
+        searcher = jax.shard_map(
+            searcher, mesh=mesh,
+            in_specs=(P(), P(data_axis), P(data_axis)),
+            out_specs=SearchResult(*(P(data_axis),) * 4),
+            check_vma=False,
         )
         res_sh = SearchResult(ids=shq, dists=shq, dc=sh1, hops=sh1)
         if not visited_adaptive:  # plain hot path: no histogram work

@@ -97,7 +97,8 @@ def eval_materialized(vectors, sq_norms, idc, queries, backend: str):
     (dots, v2) with v2 taken from the cached norm table."""
     vecs = vectors[idc]
     if backend == "ref":
-        dots = jnp.einsum("bkd,bd->bk", vecs, queries)
+        dots = jnp.einsum("bkd,bd->bk", vecs, queries,
+                          precision=jax.lax.Precision.HIGHEST)
     else:
         from repro.kernels.ops import batched_dot
 

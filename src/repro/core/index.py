@@ -375,6 +375,11 @@ class WoWIndex:
         # per-call check replays deterministically record by record)
         self._maybe_auto_compact()
         log_wal = self._wal is not None and not self._wal_replaying
+        # size the graph for the whole call up front: the device build
+        # arena then keeps one row capacity (one set of compiled shapes)
+        # across the call instead of re-uploading and recompiling at every
+        # capacity doubling
+        self.graph.ensure_capacity(self.store.n + len(attrs))
         out = []
         for s in range(0, len(attrs), batch_size):
             vs = vectors[s : s + batch_size]

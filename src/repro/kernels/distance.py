@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ops import _resolve_interpret
+
 
 def _dot_kernel(v_ref, q_ref, o_ref):
     # v_ref: [bB, bK, D], q_ref: [bB, D], o_ref: [bB, bK]
@@ -42,10 +44,7 @@ def batched_dot(
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:  # default: compiled on TPU, interpreter elsewhere
-        from .ops import _on_tpu
-
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret(interpret)
     B, K, D = vecs.shape
     bB = min(block_b, B)
     bK = min(block_k, K)

@@ -42,12 +42,7 @@ def _mamba_kernel(A_ref, dt_ref, b_ref, c_ref, x_ref, h0_ref, y_ref, hT_ref, h):
         a = jnp.exp(dti * A)  # [dti, N]
         hv = a * hv + (dt[i] * x[i])[:, None] * Bm[i][None, :]
         y = jnp.sum(hv * Cm[i][None, :], axis=1)  # [dti]
-        # all-slice index: a raw scalar dim here breaks jax<=0.4 interpret
-        pl.store(
-            y_ref,
-            (slice(0, 1), pl.dslice(i, 1), slice(None)),
-            y.astype(y_ref.dtype)[None, None, :],
-        )
+        y_ref[0:1, pl.ds(i, 1), :] = y.astype(y_ref.dtype)[None, None, :]
         return hv
 
     h[...] = jax.lax.fori_loop(0, C, step, h[...])

@@ -4,7 +4,8 @@ elsewhere.
 Policy:
   * ``backend="auto"`` — compiled Pallas on TPU, jnp reference otherwise
     (interpret mode is for correctness tests, not production CPU perf);
-  * ``backend="pallas"`` — force the kernel (interpret=True off-TPU);
+  * ``backend="pallas"`` — force the compiled kernel; raises off-TPU;
+  * ``backend="interpret"`` — the kernel in the Pallas interpreter (tests);
   * ``backend="ref"`` — force the jnp oracle.
 
 The dry-run/roofline path always lowers the reference implementations so XLA
@@ -22,21 +23,37 @@ from . import ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
+    return jax.devices()[0].platform == "tpu"
+
+
+def _resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel's ``interpret=None`` default: the compiled kernel, which
+    needs a TPU.  Interpret mode is never picked silently — off-TPU the
+    caller must ask for it."""
+    if interpret is None:
+        if not _on_tpu():
+            raise ValueError(
+                "no TPU backend: pass interpret=True to run the Pallas "
+                "kernel in the interpreter, or use the jnp reference")
         return False
+    return interpret
 
 
 def _resolve(backend: str) -> tuple[bool, bool]:
     """-> (use_pallas, interpret)."""
     if backend == "ref":
         return False, False
+    if backend == "interpret":
+        return True, True
     tpu = _on_tpu()
     if backend == "pallas":
-        return True, not tpu
+        if not tpu:
+            raise ValueError(
+                'backend="pallas" needs a TPU; use backend="interpret" for '
+                "the Pallas interpreter or \"ref\" for the jnp oracle")
+        return True, False
     if backend == "auto":
-        return (True, False) if tpu else (False, False)
+        return tpu, False
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -94,8 +111,11 @@ def merge_src_indices(pos_a, pos_b, W: int, K: int, method: str = "auto"):
       * ``"scatter"`` — one dropping scatter of source indices;
       * ``"onehot"`` — two MXU one-hot matmuls: position-equality one-hots
         contracted against the source-index iota.  Every output column has
-        exactly one hit and indices are < W+K << 2^24, so the f32
-        accumulation is exact.  Preferred on TPU, where XLA serialises
+        exactly one hit and indices are < W+K << 2^24, so the result is
+        exact provided the operands are not rounded: the contractions run
+        at ``Precision.HIGHEST`` (TPU's default rounds f32 operands to
+        bf16, exact only for integers <= 256, so ``W + K > 256`` would
+        write back wrong slots).  Preferred on TPU, where XLA serialises
         variable-index scatters;
       * ``"sort"`` — invert the position permutation with one packed
         single-key sort: ``pos * (W+K) + src`` over the concatenated
@@ -134,10 +154,12 @@ def merge_src_indices(pos_a, pos_b, W: int, K: int, method: str = "auto"):
         out = jnp.arange(W, dtype=jnp.int32)[None, None, :]
         oa = (pos_a[:, :, None] == out).astype(jnp.float32)  # [B, W, W]
         ob = (pos_b[:, :, None] == out).astype(jnp.float32)  # [B, K, W]
+        hi = jax.lax.Precision.HIGHEST
         srcf = jnp.einsum("bsw,s->bw", oa,
-                          jnp.arange(W, dtype=jnp.float32))
+                          jnp.arange(W, dtype=jnp.float32), precision=hi)
         srcf = srcf + jnp.einsum("bkw,k->bw", ob,
-                                 W + jnp.arange(K, dtype=jnp.float32))
+                                 W + jnp.arange(K, dtype=jnp.float32),
+                                 precision=hi)
         return srcf.astype(jnp.int32)
     raise ValueError(f"unknown writeback method {method!r}")
 

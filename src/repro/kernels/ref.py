@@ -23,7 +23,8 @@ def gather_dot_ref(
     table: jax.Array, ids: jax.Array, queries: jax.Array
 ) -> jax.Array:
     """out[b, k] = <table[ids[b, k]], queries[b]>  (fused gather + dot)."""
-    return jnp.einsum("bkd,bd->bk", table[ids], queries)
+    return jnp.einsum("bkd,bd->bk", table[ids], queries,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def gather_norm_dot_ref(
@@ -41,9 +42,12 @@ def gather_norm_dot_ref(
     if scales is not None:
         vecs = vecs * scales.astype(jnp.float32)[idc][..., None]
     queries = queries.astype(jnp.float32)
+    # HIGHEST: the TPU's default rounds f32 operands to bf16, which the
+    # factorised L2 (|v|^2 - 2 v.q + |q|^2) would amplify
+    hi = jax.lax.Precision.HIGHEST
     return (
-        jnp.einsum("bkd,bd->bk", vecs, queries),
-        jnp.einsum("bkd,bkd->bk", vecs, vecs),
+        jnp.einsum("bkd,bd->bk", vecs, queries, precision=hi),
+        jnp.einsum("bkd,bkd->bk", vecs, vecs, precision=hi),
     )
 
 

@@ -123,6 +123,13 @@ def main() -> None:
         ap.error("--vec-dtype int8/bf16 requires --pipeline fused (the "
                  "reference pipeline has no fused-dequant gather)")
 
+    from .device import describe_devices, use_compile_cache
+
+    use_compile_cache()
+    dev = describe_devices()
+    print(f"device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
+
     if args.trace_compiles:
         from ..analysis.compile_guard import trace_compiles
 

@@ -90,7 +90,7 @@ def test_fused_matches_reference_pipeline(metric_index):
     qs, ranges = _query_set(len(attrs), vecs.shape[1], attrs)
     ref = search_batch(snap, qs, ranges, k=10, width=48,
                        pipeline="reference", backend="ref")
-    for backend in ("ref", "auto", "pallas"):
+    for backend in ("ref", "auto", "interpret"):
         got = search_batch(snap, qs, ranges, k=10, width=48,
                            pipeline="fused", backend=backend)
         rd, gd = np.asarray(ref.dists), np.asarray(got.dists)
@@ -114,7 +114,7 @@ def test_fused_matches_host_reference(metric_index):
     snap = take_snapshot(idx)
     qs, ranges = _query_set(len(attrs), vecs.shape[1], attrs, nq=16, seed=3)
     res = search_batch(snap, qs, ranges, k=10, width=48,
-                       pipeline="fused", backend="pallas")
+                       pipeline="fused", backend="interpret")
     dev_ids = np.asarray(res.ids)
     dev_d = np.asarray(res.dists)
     overlap, dc_close = [], 0
@@ -145,7 +145,7 @@ def test_degenerate_ranges(metric_index):
     ])
     for pipeline in ("fused", "reference"):
         res = search_batch(snap, qs, ranges, k=5, width=16,
-                           pipeline=pipeline, backend="pallas")
+                           pipeline=pipeline, backend="interpret")
         ids = np.asarray(res.ids)
         # empty range: no results, no distance evaluations
         assert np.all(ids[0] == -1)

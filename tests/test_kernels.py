@@ -47,46 +47,99 @@ def test_gather_dot_sweep(n, B, K, D):
 
 
 @pytest.mark.parametrize("n,B,K,D,rows", [
-    (50, 2, 7, 16, 4),    # ragged K: padded up to a rows multiple
+    (50, 2, 7, 16, 4),    # ragged K and n: last tile clamped inside the table
     (200, 4, 33, 8, 8),
-    (33, 1, 1, 5, 8),     # rows clamped to K
+    (33, 1, 1, 5, 8),     # B padded up to one query tile
     (64, 5, 9, 128, 3),
 ])
 def test_gather_norm_dot_slab_sweep(n, B, K, D, rows):
-    """Blocked slab kernel: fused dots + in-kernel squared norms, with
-    double-buffered row DMAs and K padding."""
+    """Blocked slab kernel (``rows`` queries per grid step): fused dots +
+    in-kernel squared norms, with double-buffered tile DMAs and B/K
+    padding."""
     table = jnp.asarray(RNG.normal(size=(n, D)), jnp.float32)
     ids = jnp.asarray(RNG.integers(0, n, size=(B, K)), jnp.int32)
     qs = jnp.asarray(RNG.normal(size=(B, D)), jnp.float32)
-    dots, v2 = gather_norm_dot(table, ids, qs, rows=rows, interpret=True)
+    dots, v2 = gather_norm_dot(table, ids, qs, block_q=rows, interpret=True)
     ed, ev = ref.gather_norm_dot_ref(table, ids, qs)
     np.testing.assert_allclose(dots, ed, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(v2, ev, rtol=1e-5, atol=1e-5)
     # out-of-range ids are clipped, not OOB
     bad = jnp.full((B, K), n + 99, jnp.int32)
-    dots_b, _ = gather_norm_dot(table, bad, qs, rows=rows, interpret=True)
+    dots_b, _ = gather_norm_dot(table, bad, qs, block_q=rows, interpret=True)
     np.testing.assert_allclose(
         dots_b, jnp.broadcast_to(table[n - 1] @ qs.T, (K, B)).T, rtol=1e-5, atol=1e-5
     )
 
 
 def test_interpret_default_resolves_from_platform():
-    """The kernels' `interpret=None` default must resolve from the platform
-    (interpreter off-TPU, compiled kernel on TPU) — direct callers shouldn't
-    need to pass it.  Off-TPU this exercises the interpret fallback; on TPU
-    the same calls exercise the compiled path."""
-    table = jnp.asarray(RNG.normal(size=(20, 8)), jnp.float32)
-    ids = jnp.asarray(RNG.integers(0, 20, size=(2, 4)), jnp.int32)
+    """The kernels' `interpret=None` default resolves from the platform: the
+    compiled kernel on TPU; off-TPU it raises rather than silently running
+    the interpreter (callers ask for it with interpret=True)."""
+    from repro.kernels.ops import _on_tpu
+
+    table = jnp.asarray(RNG.normal(size=(24, 8)), jnp.float32)
+    ids = jnp.asarray(RNG.integers(0, 24, size=(2, 4)), jnp.int32)
     qs = jnp.asarray(RNG.normal(size=(2, 8)), jnp.float32)
-    dots, _ = gather_norm_dot(table, ids, qs)  # no interpret kwarg
+    vecs = jnp.asarray(RNG.normal(size=(2, 4, 8)), jnp.float32)
+    if not _on_tpu():
+        with pytest.raises(ValueError, match="no TPU backend"):
+            gather_norm_dot(table, ids, qs)  # no interpret kwarg
+        with pytest.raises(ValueError, match="no TPU backend"):
+            batched_dot(vecs, qs)
+        return
+    dots, _ = gather_norm_dot(table, ids, qs)
     np.testing.assert_allclose(
         dots, ref.gather_dot_ref(table, ids, qs), rtol=1e-5, atol=1e-5
     )
-    vecs = jnp.asarray(RNG.normal(size=(2, 4, 8)), jnp.float32)
-    out = batched_dot(vecs, qs)  # no interpret kwarg
+    out = batched_dot(vecs, qs)
     np.testing.assert_allclose(
         out, ref.batched_dot_ref(vecs, qs), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("backend", ["pallas", "interpret", "auto", "ref"])
+def test_dispatch_never_hides_the_device(backend):
+    """`backend="pallas"` off-TPU raises; the interpreter only runs when
+    asked for by name; "auto" is the jnp reference off-TPU."""
+    from repro.kernels import ops
+
+    table = jnp.asarray(RNG.normal(size=(32, 16)), jnp.float32)
+    ids = jnp.asarray(RNG.integers(0, 32, size=(3, 5)), jnp.int32)
+    qs = jnp.asarray(RNG.normal(size=(3, 16)), jnp.float32)
+    if backend == "pallas" and not ops._on_tpu():
+        with pytest.raises(ValueError, match="needs a TPU"):
+            ops.gather_norm_dot(table, ids, qs, backend=backend)
+        return
+    dots, v2 = ops.gather_norm_dot(table, ids, qs, backend=backend)
+    ed, ev = ref.gather_norm_dot_ref(table, ids, qs)
+    np.testing.assert_allclose(dots, ed, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v2, ev, rtol=1e-5, atol=1e-5)
+
+
+def test_gather_norm_dot_rejects_other_dtypes():
+    """No silent cast: a table outside {f32, bf16, int8} is an error."""
+    table = jnp.zeros((16, 8), jnp.float16)
+    ids = jnp.zeros((1, 2), jnp.int32)
+    with pytest.raises(ValueError, match="unsupported table dtype"):
+        gather_norm_dot(table, ids, jnp.zeros((1, 8)), interpret=True)
+
+
+@pytest.mark.parametrize("vec_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("n,B,K,D", [(64, 3, 17, 128), (100, 9, 5, 24)])
+def test_gather_norm_dot_quantized_slab(vec_dtype, n, B, K, D):
+    """Quantized tables: tile DMAs in storage dtype, dequant in VMEM —
+    parity with the dequantizing reference."""
+    from repro.core.store import quantize_rows
+
+    slab, scales = quantize_rows(RNG.normal(size=(n, D)), vec_dtype)
+    table = jnp.asarray(slab)
+    sc = None if scales is None else jnp.asarray(scales)
+    ids = jnp.asarray(RNG.integers(0, n, size=(B, K)), jnp.int32)
+    qs = jnp.asarray(RNG.normal(size=(B, D)), jnp.float32)
+    dots, v2 = gather_norm_dot(table, ids, qs, scales=sc, interpret=True)
+    ed, ev = ref.gather_norm_dot_ref(table, ids, qs, scales=sc)
+    np.testing.assert_allclose(dots, ed, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(v2, ev, rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("B,H,T,N,chunk", [(1, 1, 16, 8, 4), (2, 3, 64, 16, 16), (1, 2, 96, 32, 32)])
