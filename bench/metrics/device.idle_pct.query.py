@@ -1,0 +1,9 @@
+"""Share of the traced interval in which no operation ran on the device,
+in a query cell."""
+UNIT = "%"
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
