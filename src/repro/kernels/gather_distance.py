@@ -229,6 +229,7 @@ def gather_norm_dot(
             jax.ShapeDtypeStruct((Bp, kp), jnp.float32),
         ],
         interpret=interpret,
+        name="gather_norm_dot",
     )(base, *operands)
     return dots[:B, :K], v2[:B, :K]
 

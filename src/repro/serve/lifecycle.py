@@ -65,6 +65,25 @@ Determinism for tests: the clock (``now``) is injectable, and an
 ``EngineFaultPlan`` (``repro.persist.faultfs``) hooks every chunk and
 ingest apply — slow waves become virtual-clock jumps, crashes become
 ``CrashError`` at exact scheduler points.
+
+**Tracing** — the scheduler marks its host work with
+``jax.profiler.TraceAnnotation`` spans, which land in the same profiler
+trace as the device's programs and cost a few hundred nanoseconds each
+while no trace is captured (arguments are formatted only when one is).
+They nest as the code does::
+
+    serve.step                      one scheduler turn
+      serve.assemble  wave, n, bucket   queue pop, packing, uploads
+        serve.dispatch  program         _init_jit launch (returns early)
+      serve.chunk     wave, h, bucket   one hop chunk of one wave
+        serve.dispatch  program         _run_jit launch
+        serve.sync                      wait for the chunk (``active``)
+        serve.harvest   n               result reads and replies
+        serve.compact                   survivor gather
+          serve.dispatch  program       _compact_rows launch
+
+``wave`` is the wave's ordinal (``ServeStats.waves`` at its assembly);
+requests and replies carry it too.
 """
 from __future__ import annotations
 
@@ -74,6 +93,7 @@ from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.device_search import (
     _MIN_BUCKET,
@@ -93,9 +113,10 @@ class ServeStats:
     """Request-lifecycle counters + latency accounting — the one source of
     truth shared by the engine, ``RagPipeline.stats()`` and the benches.
 
-    Latency is admission(arrival)->reply, recorded in a bounded reservoir
-    (the most recent ``reservoir`` samples) so a long-running server's
-    percentiles track current behavior at O(1) memory."""
+    Latency is admission(arrival)->reply and queue wait is
+    admission->dispatch of the request's wave, both recorded in bounded
+    reservoirs (the most recent ``reservoir`` samples) so a long-running
+    server's percentiles track current behavior at O(1) memory."""
 
     def __init__(self, reservoir: int = 4096):
         self.submitted = 0
@@ -113,14 +134,17 @@ class ServeStats:
         self.shed_waves = 0  # waves assembled at the shed width cap
         self.queue_peak = 0
         self._lat = deque(maxlen=reservoir)
+        self._wait = deque(maxlen=reservoir)
         self._t0: float | None = None
         self._t1: float | None = None
 
-    def note_reply(self, now: float, latency_s: float, degraded: bool) -> None:
+    def note_reply(self, now: float, latency_s: float, degraded: bool,
+                   wait_s: float = 0.0) -> None:
         self.served += 1
         if degraded:
             self.degraded += 1
         self._lat.append(latency_s)
+        self._wait.append(wait_s)
         if self._t0 is None:
             self._t0 = now - latency_s
         self._t1 = now
@@ -162,6 +186,10 @@ class ServeStats:
             },
         }
         out.update(self.latency_percentiles())
+        q = (np.percentile(np.asarray(self._wait), [50, 95]) * 1e3
+             if self._wait else (0.0, 0.0))
+        out.update(queue_wait_p50_ms=float(q[0]),
+                   queue_wait_p95_ms=float(q[1]))
         return out
 
 
@@ -176,6 +204,8 @@ class Request:
     k: int
     deadline: float  # absolute clock time; +inf = none
     arrival_t: float
+    wave: int = -1  # ordinal of the wave it runs in; -1 = still queued
+    start_t: float | None = None  # clock time its wave's init dispatched
 
 
 @dataclass
@@ -184,7 +214,10 @@ class Reply:
     answer was produced under a reduced hop budget (deadline pressure) or
     after its deadline; ``reason`` is None for a full-budget in-deadline
     answer, else ``"deadline"`` (truncated in flight) or
-    ``"queue_deadline"`` (expired before execution, ids empty)."""
+    ``"queue_deadline"`` (expired before execution, ids empty).
+    ``wait_s`` is the queue wait, admission to the dispatch of the
+    request's wave (its whole latency when it expired in the queue);
+    ``wave`` that wave's ordinal (-1 when it never ran)."""
 
     rid: int
     ids: np.ndarray  # i64[k] external (index) ids, -1 padded
@@ -195,6 +228,8 @@ class Reply:
     dc: int
     latency_s: float
     finish_t: float
+    wait_s: float = 0.0
+    wave: int = -1
 
 
 @dataclass
@@ -337,6 +372,7 @@ class _Wave:
     next_h: int
     t_planned: int = 0
     shed: bool = False  # assembled under the shed width cap
+    wid: int = 0  # wave ordinal, shared by its spans and requests
 
 
 # -------------------------------------------------------------------- engine
@@ -570,29 +606,31 @@ class ServeEngine:
         """One scheduler turn: expire stale queued requests, give ingest
         its fair share, assemble a wave if there is capacity, run one hop
         chunk of one in-flight wave.  Returns the replies produced."""
-        now = self._now()
-        replies: list[Reply] = []
-        self._expire_queued(now, replies)
-        if self._ingest_q:
-            self._ingest_credit += self.config.ingest_share
-            if self._ingest_credit >= 1.0 or not (self._queue or self._waves):
-                self._ingest_credit = max(0.0, self._ingest_credit - 1.0)
-                self._apply_ingest_one()
-        free = self.config.max_slots - self.in_flight
-        # batching policy: while waves are in flight, let arrivals
-        # accumulate into a full-width wave (small waves waste the jitted
-        # pipeline); once the engine is idle, take whatever is queued.
-        # Cannot starve: when the last wave retires, the next step
-        # assembles a partial wave unconditionally.
-        full = self.config.shed_wave if self.overloaded() else \
-            self.config.max_wave
-        if self._queue and free > 0 and (
-            not self._waves or len(self._queue) >= full
-        ):
-            self._assemble_wave(free)
-        if self._waves:
-            replies.extend(self._run_chunk())
-        return replies
+        with TraceAnnotation("serve.step"):
+            now = self._now()
+            replies: list[Reply] = []
+            self._expire_queued(now, replies)
+            if self._ingest_q:
+                self._ingest_credit += self.config.ingest_share
+                if (self._ingest_credit >= 1.0
+                        or not (self._queue or self._waves)):
+                    self._ingest_credit = max(0.0, self._ingest_credit - 1.0)
+                    self._apply_ingest_one()
+            free = self.config.max_slots - self.in_flight
+            # batching policy: while waves are in flight, let arrivals
+            # accumulate into a full-width wave (small waves waste the
+            # jitted pipeline); once the engine is idle, take whatever is
+            # queued.  Cannot starve: when the last wave retires, the next
+            # step assembles a partial wave unconditionally.
+            full = self.config.shed_wave if self.overloaded() else \
+                self.config.max_wave
+            if self._queue and free > 0 and (
+                not self._waves or len(self._queue) >= full
+            ):
+                self._assemble_wave(free)
+            if self._waves:
+                replies.extend(self._run_chunk())
+            return replies
 
     def drain(self, max_steps: int = 1_000_000) -> list[Reply]:
         """Step until idle; the step bound turns a scheduler deadlock into
@@ -715,27 +753,35 @@ class ServeEngine:
         take = min(cap, free, len(self._queue))
         if take <= 0:
             return
-        self._refresh_snapshot()
-        snap, di = self._snap, self._di
-        reqs = [self._queue.popleft() for _ in range(take)]
-        wcfg = self._wave_cfg(snap)
-        chunk = self._chunk_schedule()
+        wid = self.stats.waves
         Bp = _pow2ceil(max(take, _MIN_BUCKET))
-        qp = np.zeros((Bp, snap.vectors.shape[1]), np.float32)
-        rp = np.tile(np.asarray([[1.0, 0.0]], np.float32), (Bp, 1))
-        dl = np.full(Bp, np.inf)
-        for i, r in enumerate(reqs):
-            qp[i] = r.query
-            rp[i] = r.rng
-            dl[i] = r.deadline
-        st = _init_jit(di, jnp.asarray(qp), jnp.asarray(rp), wcfg)
-        orig = np.concatenate(
-            [np.arange(take), np.full(Bp - take, -1)]
-        ).astype(np.int64)
-        self._waves.append(_Wave(
-            st=st, cfg=wcfg, di=di, ids_map=snap.ids_map, reqs=reqs,
-            orig=orig, dl=dl, chunk=chunk, next_h=chunk[0], shed=shed,
-        ))
+        with TraceAnnotation("serve.assemble", wave=wid, n=take, bucket=Bp):
+            self._refresh_snapshot()
+            snap, di = self._snap, self._di
+            reqs = [self._queue.popleft() for _ in range(take)]
+            wcfg = self._wave_cfg(snap)
+            chunk = self._chunk_schedule()
+            qp = np.zeros((Bp, snap.vectors.shape[1]), np.float32)
+            rp = np.tile(np.asarray([[1.0, 0.0]], np.float32), (Bp, 1))
+            dl = np.full(Bp, np.inf)
+            for i, r in enumerate(reqs):
+                qp[i] = r.query
+                rp[i] = r.rng
+                dl[i] = r.deadline
+            qd, rd = jnp.asarray(qp), jnp.asarray(rp)
+            start = self._now()
+            for r in reqs:
+                r.wave, r.start_t = wid, start
+            with TraceAnnotation("serve.dispatch", program="_init_jit"):
+                st = _init_jit(di, qd, rd, wcfg)
+            orig = np.concatenate(
+                [np.arange(take), np.full(Bp - take, -1)]
+            ).astype(np.int64)
+            self._waves.append(_Wave(
+                st=st, cfg=wcfg, di=di, ids_map=snap.ids_map, reqs=reqs,
+                orig=orig, dl=dl, chunk=chunk, next_h=chunk[0], shed=shed,
+                wid=wid,
+            ))
         self.stats.waves += 1
         if shed:
             self.stats.shed_waves += 1
@@ -745,9 +791,18 @@ class ServeEngine:
             self.fault_plan.on_chunk()
         w = self._waves[self._rr % len(self._waves)]
         h = w.next_h
+        with TraceAnnotation("serve.chunk", wave=w.wid, h=h,
+                             bucket=len(w.orig)):
+            replies = self._chunk(w, h)
+        self._rr += 1
+        return replies
+
+    def _chunk(self, w: _Wave, h: int) -> list[Reply]:
         t0 = self._now()
-        w.st = _run_jit(w.di, w.st, w.cfg, h)
-        act = np.asarray(w.st.active)  # the chunk-boundary sync point
+        with TraceAnnotation("serve.dispatch", program="_run_jit"):
+            w.st = _run_jit(w.di, w.st, w.cfg, h)
+        with TraceAnnotation("serve.sync"):
+            act = np.asarray(w.st.active)  # the chunk-boundary sync point
         now = self._now()
         self.stats.chunks += 1
         w.t_planned += h
@@ -771,26 +826,27 @@ class ServeEngine:
         harvest = finished | blown | (real & act & budget_out)
         replies: list[Reply] = []
         if harvest.any():
-            res_i = np.asarray(w.st.res_i)
-            res_d = np.asarray(w.st.res_d)
-            dc = np.asarray(w.st.dc)
-            hops = np.asarray(w.st.hops)
-            hist = np.bincount(hops[harvest], minlength=1)
-            self._recent_hists.append(hist.astype(np.int64))
-            for slot in np.flatnonzero(harvest):
-                req = w.reqs[w.orig[slot]]
-                truncated = bool(act[slot]) and bool(blown[slot])
-                late = now > req.deadline
-                ids = res_i[slot, : req.k]
-                mapped = np.where(
-                    ids >= 0, w.ids_map[np.clip(ids, 0, None)], -1
-                ).astype(np.int64)
-                replies.append(self._reply(
-                    req, mapped, res_d[slot, : req.k].copy(),
-                    hops=int(hops[slot]), dc=int(dc[slot]), now=now,
-                    degraded=truncated or late,
-                    reason="deadline" if (truncated or late) else None,
-                ))
+            with TraceAnnotation("serve.harvest", n=int(harvest.sum())):
+                res_i = np.asarray(w.st.res_i)
+                res_d = np.asarray(w.st.res_d)
+                dc = np.asarray(w.st.dc)
+                hops = np.asarray(w.st.hops)
+                hist = np.bincount(hops[harvest], minlength=1)
+                self._recent_hists.append(hist.astype(np.int64))
+                for slot in np.flatnonzero(harvest):
+                    req = w.reqs[w.orig[slot]]
+                    truncated = bool(act[slot]) and bool(blown[slot])
+                    late = now > req.deadline
+                    ids = res_i[slot, : req.k]
+                    mapped = np.where(
+                        ids >= 0, w.ids_map[np.clip(ids, 0, None)], -1
+                    ).astype(np.int64)
+                    replies.append(self._reply(
+                        req, mapped, res_d[slot, : req.k].copy(),
+                        hops=int(hops[slot]), dc=int(dc[slot]), now=now,
+                        degraded=truncated or late,
+                        reason="deadline" if (truncated or late) else None,
+                    ))
         live = real & act & ~harvest
         nlive = int(np.sum(live))
         if nlive == 0:
@@ -803,23 +859,28 @@ class ServeEngine:
             Bn = min(len(w.orig), _pow2ceil(max(nlive, _MIN_BUCKET)))
             rows = np.flatnonzero(live)
             if Bn < len(w.orig):  # bucket shrinks: gather the survivors
-                idx = np.concatenate(
-                    [rows, np.full(Bn - nlive, rows[0])]
-                )
-                w.st = _compact_rows(w.st, jnp.asarray(idx), jnp.int32(nlive))
-                w.orig = np.where(np.arange(Bn) < nlive, w.orig[idx], -1)
-                w.dl = w.dl[idx]
+                with TraceAnnotation("serve.compact"):
+                    idx = np.concatenate(
+                        [rows, np.full(Bn - nlive, rows[0])]
+                    )
+                    with TraceAnnotation("serve.dispatch",
+                                         program="_compact_rows"):
+                        w.st = _compact_rows(w.st, jnp.asarray(idx),
+                                             jnp.int32(nlive))
+                    w.orig = np.where(np.arange(Bn) < nlive, w.orig[idx], -1)
+                    w.dl = w.dl[idx]
             else:  # same bucket: just retire the harvested slots
                 w.orig[harvest] = -1
             w.next_h = w.chunk[1]
-        self._rr += 1
         return replies
 
     def _reply(self, req: Request, ids: np.ndarray, dists: np.ndarray,
                hops: int, dc: int, now: float, degraded: bool,
                reason: str | None) -> Reply:
         lat = max(now - req.arrival_t, 0.0)
-        self.stats.note_reply(now, lat, degraded)
+        start = now if req.start_t is None else req.start_t
+        wait = max(start - req.arrival_t, 0.0)
+        self.stats.note_reply(now, lat, degraded, wait)
         return Reply(rid=req.rid, ids=ids, dists=dists, degraded=degraded,
                      reason=reason, hops=hops, dc=dc, latency_s=lat,
-                     finish_t=now)
+                     finish_t=now, wait_s=wait, wave=req.wave)

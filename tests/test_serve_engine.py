@@ -620,8 +620,169 @@ def test_stats_accounting_consistency(wl, idx):
     assert s["submitted"] == s["admitted"] + s["rejected"]
     assert s["served"] == s["admitted"] == 16
     assert 0 < s["p50_ms"] <= s["p95_ms"] <= s["p99_ms"]
+    # each request's wait is part of its latency, so the percentiles are too
+    assert 0 <= s["queue_wait_p50_ms"] <= s["queue_wait_p95_ms"] \
+        <= s["p95_ms"]
     assert s["qps"] > 0
     assert s["shed_fraction"] == pytest.approx(8 / 24)
     es = eng.engine_stats()
     assert es["queue_len"] == 0 and es["in_flight"] == 0
     assert es["pending_ingest"] == 0
+
+
+# ------------------------------------------------------------------ tracing
+def _drip(eng, wl):
+    """Submit 16, then one request per step, then drain: several waves in
+    flight at once, with compactions (as the bitwise-parity test)."""
+    got = []
+    for i in range(16):
+        eng.submit(wl.queries[i], wl.ranges[i])
+    for i in range(16, len(wl.queries)):
+        got.extend(eng.step())
+        eng.submit(wl.queries[i], wl.ranges[i])
+    got.extend(eng.drain())
+    return {r.rid: r for r in got}
+
+
+def _serve_spans(trace_dir):
+    """-> [(name, start, end, args)] of the ``serve.*`` host spans."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    (f,) = Path(trace_dir).rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(f)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    s = int(ev.start_ns)
+                    out.append((ev.name, s, s + int(ev.duration_ns),
+                                dict(ev.stats)))
+    return sorted(out, key=lambda x: (x[1], -x[2]))
+
+
+def _traced(fn, trace_dir):
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        return fn()
+    finally:
+        jax.profiler.stop_trace()
+
+
+def test_serve_spans_nest_and_share_wave_ids(tmp_path, wl, idx):
+    """Under ``jax.profiler`` the engine's spans nest as the scheduler
+    does, one ``serve.chunk`` per chunk, every launch under the work that
+    issued it, and a wave's spans and replies share its id."""
+    eng = _engine(idx, max_wave=16)
+    eng.warmup()
+    chunks0 = eng.stats.chunks
+    replies = _traced(lambda: _drip(eng, wl), tmp_path)
+    spans = _serve_spans(tmp_path)
+    parent = {}
+    for i, (n, s, e, _) in enumerate(spans):
+        # sorted by start (outer first on ties): the innermost cover is the
+        # last earlier span that still covers this one
+        up = [j for j in range(i) if spans[j][1] <= s and e <= spans[j][2]]
+        parent[i] = spans[up[-1]][0] if up else None
+    allowed = {
+        "serve.step": {None},
+        "serve.assemble": {"serve.step"},
+        "serve.chunk": {"serve.step"},
+        "serve.dispatch": {"serve.assemble", "serve.chunk", "serve.compact"},
+        "serve.sync": {"serve.chunk"},
+        "serve.harvest": {"serve.chunk"},
+        "serve.compact": {"serve.chunk"},
+    }
+    for i, (n, *_) in enumerate(spans):
+        assert parent[i] in allowed[n], (n, parent[i])
+    names = [n for n, *_ in spans]
+    assert names.count("serve.chunk") == eng.stats.chunks - chunks0
+    assert "serve.compact" in names  # the drip shrinks waves
+    programs = {a["program"] for n, _, _, a in spans if n == "serve.dispatch"}
+    assert programs == {"_init_jit", "_run_jit", "_compact_rows"}
+    # wave ids: assembled once, chunked after, and carried by the replies
+    assembled = {a["wave"]: (s, a["n"]) for n, s, _, a in spans
+                 if n == "serve.assemble"}
+    assert sorted(assembled) == list(range(eng.stats.waves))
+    for n, s, _, a in spans:
+        if n == "serve.chunk":
+            assert assembled[a["wave"]][0] < s
+    per_wave = {}
+    for r in replies.values():
+        per_wave[r.wave] = per_wave.get(r.wave, 0) + 1
+    assert per_wave == {w: n for w, (_, n) in assembled.items()}
+    assert sum(a["n"] for n, _, _, a in spans if n == "serve.harvest") \
+        == len(replies)
+
+
+def test_inactive_spans_format_no_arguments():
+    """With no trace being captured a span's arguments are never
+    formatted, so the engine's spans need no guard on the hot path."""
+    from jax.profiler import TraceAnnotation
+
+    class Loud:
+        formatted = 0
+
+        def __str__(self):
+            Loud.formatted += 1
+            return "x"
+
+        __repr__ = __format__ = lambda self, *a: str(self)
+
+    for _ in range(3):
+        with TraceAnnotation("serve.chunk", wave=Loud(), h=Loud()):
+            pass
+    assert Loud.formatted == 0
+
+
+def test_replies_bitwise_equal_with_profiler_capturing(tmp_path, wl, idx):
+    """Capturing a trace changes no answer: ids, distances, hops and
+    distance counts are bitwise those of an untraced run."""
+    plain = _drip(_engine(idx, max_wave=16), wl)
+    traced = _traced(lambda: _drip(_engine(idx, max_wave=16), wl), tmp_path)
+    assert sorted(plain) == sorted(traced)
+    for rid, a in plain.items():
+        b = traced[rid]
+        assert np.array_equal(a.ids, b.ids)
+        assert np.array_equal(a.dists, b.dists)
+        assert (a.hops, a.dc, a.wave) == (b.hops, b.dc, b.wave)
+
+
+def test_queue_wait_is_exact_under_a_virtual_clock(wl, idx):
+    """``Reply.wait_s`` runs from admission to the dispatch of the
+    request's wave, read from the engine's clock: exact under a virtual
+    one, never negative and never past the latency; a request that
+    expires in the queue waited its whole latency."""
+    clk = VClock()
+    eng = ServeEngine(index=idx, now=clk, config=EngineConfig(**SEARCH))
+    full = (wl.attrs.min(), wl.attrs.max())  # all in range: many hops
+    a = eng.submit(wl.queries[0], full)
+    clk.advance(0.25)
+    b = eng.submit(wl.queries[1], full)
+    clk.advance(0.75)  # t = 1: the first step assembles both
+    got = eng.step()
+    # a wave is in flight and the queue is short of a full wave: c waits
+    c = eng.submit(wl.queries[2], full, timeout_s=0.5)
+    while not eng.idle:
+        clk.advance(0.125)
+        got.extend(eng.step())
+    replies = {r.rid: r for r in got}
+    assert replies[a.rid].wait_s == 1.0
+    assert replies[b.rid].wait_s == 0.75
+    assert replies[a.rid].wave == replies[b.rid].wave == 0
+    exp = replies[c.rid]
+    assert exp.reason == "queue_deadline" and exp.wave == -1
+    assert exp.wait_s == exp.latency_s > 0.5
+    for r in replies.values():
+        assert 0.0 <= r.wait_s <= r.latency_s
+    waits = np.asarray([r.wait_s for r in replies.values()])
+    s = eng.stats.summary()
+    assert s["queue_wait_p50_ms"] == pytest.approx(
+        np.percentile(waits, 50) * 1e3)
+    assert s["queue_wait_p95_ms"] == pytest.approx(
+        np.percentile(waits, 95) * 1e3)

@@ -64,6 +64,24 @@ def test_gather_norm_dot_compiles_for_v5e(one_chip, D, dtype):
     assert "tpu_custom_call" in text
 
 
+def test_gather_kernel_keeps_its_name_for_v5e(one_chip):
+    """The kernel's custom call is named ``gather_norm_dot`` whatever jit
+    calls it, so the trace's ``gather_norm_dot.<i>`` events (the roofline
+    reader's input) survive a refactor of the code around it."""
+    from repro.kernels.gather_distance import gather_norm_dot
+
+    def caller_of_another_name(t, i, q):
+        return gather_norm_dot.__wrapped__(t, i, q, interpret=False)
+
+    args = (_spec((1 << 16, 128), jnp.float32, one_chip),
+            _spec((64, 17), jnp.int32, one_chip),
+            _spec((64, 128), jnp.float32, one_chip))
+    text = jax.jit(caller_of_another_name).lower(*args).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(c.startswith("%gather_norm_dot") for c in calls)
+
+
 @pytest.mark.parametrize("visited", ["hash", "bitmap"])
 def test_serve_chunk_compiles_for_v5e(one_chip, as_on_tpu, visited):
     """The serve engine's chunk jit (``_run_jit``) at n = 2^20, d = 128,
