@@ -11,7 +11,9 @@ donates that parameter too), then checks each call site: a donated
 ``Name``/``self.attr`` argument must not be *loaded* again in a later
 statement of the same block unless rebound first.  The idiomatic safe
 shape — ``self.vectors = arena_scatter(self.vectors, ...)`` — rebinds
-in the same statement and passes.
+in the same statement and passes, also where that statement sits inside
+a ``with`` or loop body; a donation inside such a body is still checked
+against the statements after the block.
 """
 from __future__ import annotations
 
@@ -87,15 +89,29 @@ def _loads_in(stmt: ast.stmt, name: str) -> ast.AST | None:
     return None
 
 
+def _calls_with_owner(stmt: ast.stmt):
+    """Each call inside ``stmt`` with the innermost statement holding it,
+    whose targets decide whether the call rebinds what it donated."""
+    stack: list[ast.AST] = [stmt]
+    while stack:
+        owner = stack.pop()
+        for node in ast.iter_child_nodes(owner):
+            if isinstance(node, (ast.stmt, ast.excepthandler)):
+                stack.append(node)
+                continue
+            for sub in ast.walk(node):
+                if isinstance(sub, ast.Call):
+                    yield sub, owner
+
+
 def _check_block(index: RepoIndex, fi: FuncInfo, body: list[ast.stmt],
                  don: dict[str, set[int]], out: list[Finding]) -> None:
     for i, stmt in enumerate(body):
-        for call in (n for n in ast.walk(stmt)
-                     if isinstance(n, ast.Call)):
+        for call, owner in _calls_with_owner(stmt):
             callee = index.resolve_call(fi.mod, call.func, fi.cls)
             if callee is None or callee.qualname not in don:
                 continue
-            rebound = _target_names(stmt)
+            rebound = _target_names(owner)
             for pos in don[callee.qualname]:
                 if pos >= len(call.args):
                     continue

@@ -508,7 +508,7 @@ def _serve_engine(args, wl, idx, snap, recall) -> None:
           + (f" (offered {args.rate:.0f} QPS open-loop)"
              if period else " (closed burst)"))
     print(f"shutdown summary: waves={s['waves']} chunks={s['chunks']} "
-          f"shed_waves={s['shed_waves']} queue_peak={s['queue_peak']} "
+          f"backlog_waves={s['backlog_waves']} queue_peak={s['queue_peak']} "
           f"ingest_batches={s['ingest']['batches']} "
           f"ingest_rows={s['ingest']['rows']} "
           f"applied_lsn={s['applied_lsn']}")

@@ -9,14 +9,14 @@ four explicit stages:
 absolute deadline (``timeout_s`` from the injected clock).  When the queue
 reaches ``queue_cap`` the request is rejected with a ``retry_after``
 estimate derived from the live service rate — backpressure is a first-class
-reply, never unbounded queue growth.  Sustained pressure (the queue riding
-above ``high_water`` across consecutive submissions) flips the engine into
-load-shedding mode.
+reply, never unbounded queue growth.
 
 **Scheduling** — waves are assembled from the queue head into power-of-two
 buckets (one compilation per bucket, exactly like ``search_batch``) and
 tracked as slot-based in-flight state.  The hop loop runs as resumable
-chunks (``device_search._run_jit`` over an explicit ``HopState``); at every
+chunks (``device_search._run_jit_inplace``: one launch that updates the
+wave's ``HopState`` in place and returns a packed record of what the host
+needs, read back in one transfer); at every
 chunk boundary finished requests are harvested and *replied immediately*,
 survivors are compacted into smaller buckets, and newly admitted requests
 start as fresh waves that interleave round-robin with the stragglers — the
@@ -24,7 +24,10 @@ ragged-batch compaction machinery promoted from intra-batch to
 cross-request, so a short query never waits on another request's straggler.
 Ingest rides the same scheduler through a deficit counter
 (``ingest_share``): builds and queries make progress under one loop, and
-ingest drains opportunistically when queries are idle.
+ingest drains opportunistically when queries are idle.  While waves are
+in flight a new wave starts only at full width (``max_wave`` requests
+queued and as many slots free), so a backlog — after a host stall, say —
+is served by full-width waves and clears at the engine's best rate.
 
 **Execution** — the current jitted hop pipeline, with the two previously
 static knobs driven per-wave by the live hop histogram: the hashed visited
@@ -43,9 +46,10 @@ answer prefix at every iteration) and marked ``degraded=True`` — a reduced
 hop budget, never a timeout.  A reply that lands past its deadline for any
 reason carries the flag too, so "no reply after deadline without
 ``degraded``" holds by construction.  Requests that expire while still
-queued are answered empty-and-degraded.  Under sustained overload the
-engine caps wave width (``shed_wave``) so per-wave latency stays bounded
-while admission rejects the excess — shed, don't collapse.
+queued are answered empty-and-degraded.  Load beyond what the engine
+serves is shed by admission alone: the queue stops at ``queue_cap`` and
+rejects with ``retry_after``; waves never narrow under a backlog, because
+a narrower wave serves less at the same host cost per chunk.
 
 **WAL-backed ingest** — ``submit_ingest`` validates rows individually
 (bad rows are *rejected*, good rows proceed — the explicit
@@ -76,9 +80,9 @@ They nest as the code does::
       serve.assemble  wave, n, bucket   queue pop, packing, uploads
         serve.dispatch  program         _init_jit launch (returns early)
       serve.chunk     wave, h, bucket   one hop chunk of one wave
-        serve.dispatch  program         _run_jit launch
-        serve.sync                      wait for the chunk (``active``)
-        serve.harvest   n               result reads and replies
+        serve.dispatch  program         _run_jit_inplace launch
+        serve.sync                      the chunk's record: its one read
+        serve.harvest   n               replies built from the record
         serve.compact                   survivor gather
           serve.dispatch  program       _compact_rows launch
 
@@ -100,7 +104,8 @@ from ..core.device_search import (
     _compact_rows,
     _init_jit,
     _pow2ceil,
-    _run_jit,
+    _run_jit_inplace,
+    chunk_record,
     chunk_schedule_from_hist,
     hop_cfg,
     to_device_index,
@@ -131,7 +136,7 @@ class ServeStats:
         self.ingest_replayed = 0  # applied from a pre-crash WAL suffix
         self.waves = 0
         self.chunks = 0
-        self.shed_waves = 0  # waves assembled at the shed width cap
+        self.backlog_waves = 0  # waves assembled while others ran
         self.queue_peak = 0
         self._lat = deque(maxlen=reservoir)
         self._wait = deque(maxlen=reservoir)
@@ -175,7 +180,7 @@ class ServeStats:
                               if self.submitted else 0.0),
             "waves": self.waves,
             "chunks": self.chunks,
-            "shed_waves": self.shed_waves,
+            "backlog_waves": self.backlog_waves,
             "queue_peak": self.queue_peak,
             "qps": self.qps(),
             "ingest": {
@@ -313,11 +318,10 @@ def validate_rows(vectors: np.ndarray, attrs: np.ndarray,
 @dataclass
 class EngineConfig:
     """Static engine knobs.  Search knobs mirror ``search_batch``; the
-    lifecycle knobs bound queue memory (``queue_cap``), wave shape
-    (``max_wave``/``max_slots``), overload response (``high_water``,
-    ``shed_after``, ``shed_wave``) and ingest fairness (``ingest_share`` =
-    fraction of scheduler turns ingest may consume while queries are
-    pending; 0.5 = strict alternation)."""
+    lifecycle knobs bound queue memory (``queue_cap``, beyond which
+    admission rejects), wave shape (``max_wave``/``max_slots``) and ingest
+    fairness (``ingest_share`` = fraction of scheduler turns ingest may
+    consume while queries are pending; 0.5 = strict alternation)."""
 
     k: int = 10
     width: int = 64
@@ -333,9 +337,6 @@ class EngineConfig:
     max_wave: int = 64
     max_slots: int = 256
     queue_cap: int = 512
-    high_water: int | None = None  # default queue_cap // 2
-    shed_after: int = 3  # consecutive high-pressure observations
-    shed_wave: int = 16
     default_timeout_s: float | None = None
     ingest_share: float = 0.5
     ingest_batch: int = 128
@@ -349,8 +350,6 @@ class EngineConfig:
                 f"vec_dtype must be one of {VEC_DTYPES}, "
                 f"got {self.vec_dtype!r}"
             )
-        if self.high_water is None:
-            self.high_water = max(1, self.queue_cap // 2)
         if not 0.0 <= self.ingest_share <= 1.0:
             raise ValueError("ingest_share must be in [0, 1]")
         if self.queue_cap < 1 or self.max_wave < 1 or self.max_slots < 1:
@@ -371,7 +370,6 @@ class _Wave:
     chunk: tuple[int, int]
     next_h: int
     t_planned: int = 0
-    shed: bool = False  # assembled under the shed width cap
     wid: int = 0  # wave ordinal, shared by its spans and requests
 
 
@@ -417,7 +415,6 @@ class ServeEngine:
         self._rr = 0  # round-robin cursor over in-flight waves
         self._next_rid = 0
         self._ingest_credit = 0.0
-        self._pressure = 0  # consecutive over-high-water observations
         self._recent_hists: deque = deque(maxlen=self.config.hist_window)
         self._hop_s = 0.0  # EWMA wall seconds per hop chunk-iteration
         self._wave_s = 0.0  # EWMA wall seconds per executed chunk
@@ -439,9 +436,6 @@ class ServeEngine:
     def idle(self) -> bool:
         return not (self._queue or self._waves or self._ingest_q)
 
-    def overloaded(self) -> bool:
-        return self._pressure >= self.config.shed_after
-
     def hop_histogram(self) -> np.ndarray | None:
         """Rolling hop histogram over the last ``hist_window`` waves."""
         if not self._recent_hists:
@@ -459,7 +453,6 @@ class ServeEngine:
             queue_len=self.queue_len,
             in_flight=self.in_flight,
             pending_ingest=self.pending_ingest,
-            overloaded=self.overloaded(),
             applied_lsn=(self.index._applied_lsn
                          if self.index is not None else 0),
             chunk_schedule=list(self._chunk_schedule()),
@@ -480,13 +473,8 @@ class ServeEngine:
         qlen = len(self._queue)
         if qlen >= cfg.queue_cap:
             self.stats.rejected += 1
-            self._pressure += 1
             return Rejected(rid=rid, retry_after=self._retry_after(),
                             queue_len=qlen)
-        if qlen >= cfg.high_water:
-            self._pressure += 1
-        elif qlen < cfg.high_water // 2:
-            self._pressure = max(0, self._pressure - 1)
         if timeout_s is None:
             timeout_s = cfg.default_timeout_s
         deadline = now + timeout_s if timeout_s is not None else np.inf
@@ -617,15 +605,16 @@ class ServeEngine:
                     self._ingest_credit = max(0.0, self._ingest_credit - 1.0)
                     self._apply_ingest_one()
             free = self.config.max_slots - self.in_flight
-            # batching policy: while waves are in flight, let arrivals
-            # accumulate into a full-width wave (small waves waste the
-            # jitted pipeline); once the engine is idle, take whatever is
-            # queued.  Cannot starve: when the last wave retires, the next
-            # step assembles a partial wave unconditionally.
-            full = self.config.shed_wave if self.overloaded() else \
-                self.config.max_wave
-            if self._queue and free > 0 and (
-                not self._waves or len(self._queue) >= full
+            # batching policy: while waves are in flight, a new wave starts
+            # only at full width — arrivals accumulate, and slots free up —
+            # since a chunk costs the host about the same at any width, so
+            # narrow waves would serve a backlog at a lower rate; once the
+            # engine is idle, take whatever is queued.  Cannot starve: when
+            # the last wave retires, the next step assembles a partial wave
+            # unconditionally.
+            full = min(self.config.max_wave, self.config.max_slots)
+            if self._queue and (
+                not self._waves or min(len(self._queue), free) >= full
             ):
                 self._assemble_wave(free)
             if self._waves:
@@ -736,8 +725,8 @@ class ServeEngine:
             rp = jnp.tile(jnp.asarray([[1.0, 0.0]], jnp.float32), (B, 1))
             st = _init_jit(di, qp, rp, wcfg)
             for h in dict.fromkeys(chunk):  # (h0, h), deduped
-                st = _run_jit(di, st, wcfg, h)
-            states[B] = st
+                st, _ = _run_jit_inplace(di, st, wcfg, h)
+            states[B] = st  # never donated again: compacted below
         for B in buckets:
             for Bn in buckets:
                 if Bn < B:
@@ -748,9 +737,7 @@ class ServeEngine:
 
     def _assemble_wave(self, free: int) -> None:
         cfg = self.config
-        shed = self.overloaded()
-        cap = cfg.shed_wave if shed else cfg.max_wave
-        take = min(cap, free, len(self._queue))
+        take = min(cfg.max_wave, free, len(self._queue))
         if take <= 0:
             return
         wid = self.stats.waves
@@ -779,12 +766,11 @@ class ServeEngine:
             ).astype(np.int64)
             self._waves.append(_Wave(
                 st=st, cfg=wcfg, di=di, ids_map=snap.ids_map, reqs=reqs,
-                orig=orig, dl=dl, chunk=chunk, next_h=chunk[0], shed=shed,
-                wid=wid,
+                orig=orig, dl=dl, chunk=chunk, next_h=chunk[0], wid=wid,
             ))
         self.stats.waves += 1
-        if shed:
-            self.stats.shed_waves += 1
+        if len(self._waves) > 1:
+            self.stats.backlog_waves += 1
 
     def _run_chunk(self) -> list[Reply]:
         if self.fault_plan is not None:
@@ -799,10 +785,12 @@ class ServeEngine:
 
     def _chunk(self, w: _Wave, h: int) -> list[Reply]:
         t0 = self._now()
-        with TraceAnnotation("serve.dispatch", program="_run_jit"):
-            w.st = _run_jit(w.di, w.st, w.cfg, h)
+        with TraceAnnotation("serve.dispatch", program="_run_jit_inplace"):
+            w.st, rec = _run_jit_inplace(w.di, w.st, w.cfg, h)
         with TraceAnnotation("serve.sync"):
-            act = np.asarray(w.st.active)  # the chunk-boundary sync point
+            # the chunk boundary's one read: it waits for the chunk
+            act, dc, hops, res_i, res_d = chunk_record(np.asarray(rec),
+                                                       w.cfg.k)
         now = self._now()
         self.stats.chunks += 1
         w.t_planned += h
@@ -827,10 +815,6 @@ class ServeEngine:
         replies: list[Reply] = []
         if harvest.any():
             with TraceAnnotation("serve.harvest", n=int(harvest.sum())):
-                res_i = np.asarray(w.st.res_i)
-                res_d = np.asarray(w.st.res_d)
-                dc = np.asarray(w.st.dc)
-                hops = np.asarray(w.st.hops)
                 hist = np.bincount(hops[harvest], minlength=1)
                 self._recent_hists.append(hist.astype(np.int64))
                 for slot in np.flatnonzero(harvest):

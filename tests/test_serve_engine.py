@@ -168,19 +168,86 @@ def test_queue_bound_and_retry_after(wl, idx):
     assert s.served == 8
 
 
-def test_overload_sheds_wave_width(wl, idx):
-    """Sustained pressure (queue above high-water across submissions)
-    flips the engine into load-shedding: waves are capped at
-    ``shed_wave`` so per-wave latency stays bounded."""
-    eng = _engine(idx, max_wave=16, queue_cap=64, high_water=4,
-                  shed_after=2, shed_wave=4)
-    for i in range(32):
-        eng.submit(wl.queries[i % len(wl.queries)], (0.0, 1.0))
-    assert eng.overloaded()
-    eng.drain()
+def _stalled_open_loop(wl, idx, n=200, rate=400.0, step_s=1e-3,
+                       stall_at=0.1, stall_s=0.1):
+    """Open-loop arrivals on a virtual clock, driven as one thread drives
+    the engine: before each step every query due by now is submitted,
+    and each step costs ``step_s``.  One chunk, the first after
+    ``stall_at``, stalls for ``stall_s`` (an ``EngineFaultPlan`` slow
+    chunk), so the arrivals of the stall land at once.  Returns the
+    engine, its replies by rid, and one ``(queue_len, waves in flight,
+    wave size)`` per assembled wave."""
+    clk = VClock()
+    eng = ServeEngine(index=idx, now=clk, config=EngineConfig(
+        **SEARCH, max_wave=8, max_slots=32, queue_cap=64))
+    plan = EngineFaultPlan(slow_chunk_every=1, slow_chunk_s=stall_s,
+                           sleep=clk.advance)
+    waves = []
+    assemble = eng._assemble_wave
+
+    def spy(free):
+        q0, w0 = eng.queue_len, len(eng._waves)
+        assemble(free)
+        waves.append((q0, w0, q0 - eng.queue_len))
+
+    eng._assemble_wave = spy
+    due = np.arange(n) / rate
+    i, replies, stalled = 0, {}, False
+    while i < n or not eng.idle:
+        while i < n and due[i] <= clk():
+            eng.submit(wl.queries[i % len(wl.queries)],
+                       wl.ranges[i % len(wl.ranges)])
+            i += 1
+        if eng.idle:
+            clk.t = due[i]
+            continue
+        eng.fault_plan = plan if not stalled and clk() >= stall_at else None
+        stalled = stalled or eng.fault_plan is not None
+        replies.update((r.rid, r) for r in eng.step())
+        clk.advance(step_s)
+    assert stalled
+    return eng, replies, waves
+
+
+def test_backlog_after_stall_served_at_full_width(wl, idx):
+    """A stall that pushes the queue past ``queue_cap/2`` is served by
+    waves of exactly ``max_wave`` while the backlog holds (the engine
+    never narrows a wave to shed load), and ``backlog_waves`` counts the
+    waves assembled while others were in flight."""
+    eng, _, waves = _stalled_open_loop(wl, idx)
     s = eng.stats
-    assert s.shed_waves > 0
-    assert s.served == 32  # shedding degrades throughput shape, not answers
+    assert s.queue_peak > eng.config.queue_cap // 2
+    backlog = [size for q0, _, size in waves if q0 >= eng.config.max_wave]
+    assert len(backlog) >= 4
+    assert all(size == eng.config.max_wave for size in backlog)
+    assert s.backlog_waves == sum(1 for _, w0, _ in waves if w0 > 0)
+    assert s.backlog_waves >= len(backlog) - 1
+    assert s.summary()["backlog_waves"] == s.backlog_waves
+
+
+def test_backlog_clears_then_waves_return_to_normal_policy(wl, idx):
+    """Once the stall's backlog has cleared, waves go back to the normal
+    policy (an idle engine takes whatever is queued, so waves are
+    partial again); nothing is rejected below ``queue_cap``, every
+    admitted request is served, and the answers are those of a one-shot
+    ``search_batch``."""
+    eng, replies, waves = _stalled_open_loop(wl, idx)
+    s = eng.stats
+    assert s.queue_peak < eng.config.queue_cap
+    assert s.rejected == 0 and s.served == s.admitted == 200
+    last_full = max(j for j, (_, _, size) in enumerate(waves)
+                    if size == eng.config.max_wave)
+    after = waves[last_full + 1:]
+    assert after and all(q0 < eng.config.max_wave for q0, _, _ in after)
+    assert any(w0 == 0 and size < eng.config.max_wave
+               for _, w0, size in after)
+    ref = search_batch(take_snapshot(idx), wl.queries, wl.ranges, k=5,
+                       width=32, visited="bitmap")
+    for rid, r in replies.items():
+        q = rid % len(wl.queries)
+        assert not r.degraded
+        assert np.array_equal(r.ids, ref.ids[q])
+        assert np.array_equal(r.dists, ref.dists[q])
 
 
 def test_overload_no_congestion_collapse(wl, idx):
@@ -704,7 +771,7 @@ def test_serve_spans_nest_and_share_wave_ids(tmp_path, wl, idx):
     assert names.count("serve.chunk") == eng.stats.chunks - chunks0
     assert "serve.compact" in names  # the drip shrinks waves
     programs = {a["program"] for n, _, _, a in spans if n == "serve.dispatch"}
-    assert programs == {"_init_jit", "_run_jit", "_compact_rows"}
+    assert programs == {"_init_jit", "_run_jit_inplace", "_compact_rows"}
     # wave ids: assembled once, chunked after, and carried by the replies
     assembled = {a["wave"]: (s, a["n"]) for n, s, _, a in spans
                  if n == "serve.assemble"}

@@ -136,3 +136,43 @@ def test_build_search_compiles_for_v5e(one_chip, as_on_tpu):
              _spec((B,), jnp.bool_, one_chip)]
     text = _build_search_jit.lower(di, *args, cfg=cfg).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B", [8, 64])
+def test_inplace_chunk_aliases_state_for_v5e(one_chip, as_on_tpu, B):
+    """The serve engine's chunk entry (``_run_jit_inplace``) at the
+    benchmark's serving size, n = 2^16, d = 128, f32: the compiled
+    program writes every ``HopState`` leaf in place (an
+    ``input_output_alias`` per leaf) and builds the boundary record
+    inside its fusions, with no copy or slice op of its own — the only
+    top-level copies are the scalar ones ``_run_jit`` has too."""
+    import re
+
+    from repro.core.device_search import (
+        DeviceIndex, HopState, _init_state, _run_jit_inplace, hop_cfg,
+    )
+
+    n, d, L, m = 1 << 16, 128, 9, 16
+    di = DeviceIndex(
+        vectors=_spec((n, d), jnp.float32, one_chip),
+        sq_norms=_spec((n,), jnp.float32, one_chip),
+        attrs=_spec((n,), jnp.float32, one_chip),
+        neighbors=_spec((L, n, m), jnp.int32, one_chip),
+        uvals=_spec((n,), jnp.float32, one_chip),
+        uval_rep=_spec((n,), jnp.int32, one_chip),
+        scales=_spec((1,), jnp.float32, one_chip),
+    )
+    cfg = hop_cfg(k=10, width=64, m=m, o=4, visited="bitmap")
+    st = jax.eval_shape(
+        lambda di_, q, r: _init_state(di_, q, r, cfg), di,
+        _spec((B, d), jnp.float32, one_chip),
+        _spec((B, 2), jnp.float32, one_chip))
+    st = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), st)
+    text = _run_jit_inplace.lower(di, st, cfg=cfg, h=8).compile().as_text()
+    alias = text.split("input_output_alias=", 1)[1].split("_layout=", 1)[0]
+    outs = {int(o) for o in re.findall(r"\{(\d+)\}: \(\d+,", alias)}
+    assert outs == set(range(len(HopState._fields)))
+    entry = text[text.index("\nENTRY"):]
+    own = re.findall(r"= (\S+) (copy|copy-start|slice|slice-start|"
+                     r"concatenate|transpose)\(", entry)
+    assert all(shape.startswith("s32[]") for shape, _ in own), own
