@@ -5,13 +5,15 @@ configuration files are set from.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds 51
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --max-hops 16
+    python3 bench/control.py --workload <cell> --seeds 1,2,3 --fault no-edges
 
 The controls are the plain reference put in the program's place, one step
 of precision below what the configuration states (its
 ``reference.controls``: ``Precision.HIGH`` for float32 at HIGHEST; int4
 rows, and int8 rows at ``HIGH``, for int8 at HIGHEST).  ``--max-hops``
 plants a fault in the program instead: a hop loop that stops after that
-many hops.  Prints one JSON line per seed, then the largest reading of
+many hops; ``--fault`` plants one of ``faults.py``'s, in an ingest cell,
+once the corpus is built.  Prints one JSON line per seed, then the largest reading of
 the program and the smallest of each control for each number.
 """
 import time
@@ -27,6 +29,8 @@ from pathlib import Path  # noqa: E402
 CHECKOUT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(CHECKOUT / "src"), str(CHECKOUT)]
 
+from bench import faults  # noqa: E402
+
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
@@ -35,10 +39,12 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--max-hops", type=int, default=None,
                     help="plant a hop loop that stops after this many hops")
+    ap.add_argument("--fault", choices=faults.FAULTS, default=None,
+                    help="plant this fault of the ingest path")
     args = ap.parse_args()
 
-    from bench import chip, harness
-    from bench.spec import Layout
+    from bench import chip, corpus, harness
+    from bench.spec import Cell, Layout
 
     device = chip.find()
     if device is None:
@@ -49,15 +55,21 @@ def main() -> int:
         sound = harness.engine_config
         harness.engine_config = lambda cfg: dataclasses.replace(
             sound(cfg), max_hops=args.max_hops)
+    if args.fault is not None:
+        corpus.cached_build(Cell.load(layout, args.workload).config,
+                            layout.cache)
+        faults.plant(args.fault, setattr)
+    planted = args.max_hops is not None or args.fault is not None
     prog: dict = {}
     ctrl: dict = {}
     for seed in (int(s) for s in args.seeds.split(",")):
         res = harness.run_cell(layout, args.workload, seed, args.seconds,
                                False, time.monotonic(), device,
-                               control=args.max_hops is None)
+                               control=not planted)
         got = {k: v["value"] for k, v in res["checks"].items()}
         metrics = {k: v["value"] for k, v in res["metrics"].items()}
         print(json.dumps({"seed": seed, "max_hops": args.max_hops,
+                          "fault": args.fault,
                           "correct": res["correct"], "program": got,
                           "control": res.get("control"),
                           "metrics": metrics}), flush=True)
@@ -67,7 +79,8 @@ def main() -> int:
             low = ctrl.setdefault(name, {})
             for k, v in nums.items():
                 low[k] = min(low.get(k, v), v)
-    print(json.dumps({"max_hops": args.max_hops, "program_max": prog,
+    print(json.dumps({"max_hops": args.max_hops, "fault": args.fault,
+                      "program_max": prog,
                       "control_min": ctrl}), flush=True)
     return 0
 

@@ -2,8 +2,9 @@
 and per-layer metric readers, each in a file of its own.
 
     <root>/configs/<config>.json     sizes, build and engine settings, limits
-    <root>/traffic/<mix>.json        arrival and request parameters
-    <root>/workloads/<cell>.json     config, traffic, rate and metric names
+    <root>/traffic/<mix>.json        arrival and request parameters, and
+                                     the ingest stream's with its limits
+    <root>/workloads/<cell>.json     config, traffic, rates and metric names
     <root>/metrics/<metric>.py       UNIT and read(ctx) -> float | None
     <root>/peaks.json                chip peaks keyed by device_kind
 
@@ -82,3 +83,8 @@ class Cell:
     @property
     def rate(self) -> float:
         return float(self.spec["rate_qps"])
+
+    @property
+    def ingest_rate(self) -> float:
+        """Ingest batches per second, in a cell whose mix appends."""
+        return float(self.spec["ingest_batches_per_s"])

@@ -1,4 +1,6 @@
 """The open loop times each query from its due time, not its submission."""
+import types
+
 import numpy as np
 
 from bench import drive, traffic
@@ -71,3 +73,51 @@ def test_a_lost_reply_counts_beyond_every_reply():
     replied, lat = w.latency_ms(np.array([0.1, 0.2, 0.3]))
     np.testing.assert_array_equal(replied, [True, False, False])
     assert abs(lat[0] - 300.0) < 1e-9 and min(lat[1:]) > 1.5e3
+
+
+class _Ack:
+    def __init__(self, rows, lsn):
+        self.accepted, self.rejected, self.lsn = rows, [], lsn
+
+
+class _IngestEngine(_SlowEngine):
+    """Acks each batch at once with the next LSN; each step applies one."""
+
+    def __init__(self, clock):
+        super().__init__(clock)
+        self.logged = 0
+        self.index = types.SimpleNamespace(_applied_lsn=0)
+
+    @property
+    def idle(self):
+        return not self.q and self.index._applied_lsn == self.logged
+
+    def submit_ingest(self, vectors, attrs):
+        self.logged += 1
+        return _Ack(len(attrs), self.logged)
+
+    def step(self):
+        self.index._applied_lsn = min(self.index._applied_lsn + 1,
+                                      self.logged)
+        return super().step()
+
+
+def test_an_ingest_batch_is_visible_once_its_lsn_is_applied(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(drive.time, "sleep", lambda s: setattr(
+        clock, "t", clock.t + max(s, 1e-3)))
+    sched = traffic.Schedule(due=np.array([0.9]),
+                             queries=np.zeros((1, 2), np.float32),
+                             ranges=np.zeros((1, 2)), fractions=np.ones(1))
+    feed = traffic.IngestSchedule(due=np.array([0.0, 0.0, 0.5]),
+                                  vectors=np.zeros((3, 2, 2), np.float32),
+                                  attrs=np.zeros((3, 2)))
+    w = drive.run_window(_IngestEngine(clock), sched, seconds=1.0,
+                         clock=clock, ingest=feed)
+    log = w.ingest
+    assert log.acked.all() and list(log.lsn) == [1, 2, 3]
+    # two batches due at once: the second is applied a step after the first
+    t = log.visible_t - w.t_open
+    np.testing.assert_allclose(t[:2], [0.2, 0.4])
+    assert t[2] > 0.5 and w.t_drained >= log.visible_t.max()
+    np.testing.assert_allclose(w.visible_ms(feed.due), (t - feed.due) * 1e3)

@@ -54,3 +54,31 @@ def test_a_traced_run_reports_per_layer_metrics(layout):
     assert res["device"]["window_s"] > 0
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
 
+
+
+INGEST_METRICS = {"ingest.ack_ms_per_batch", "ingest.apply_ms_per_batch",
+                  "snapshot.refresh_ms_per_refresh",
+                  "snapshot.upload_mb_per_refresh"}
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_an_ingest_cell_runs_end_to_end(layout, trace):
+    res = _run(layout, "tiny.ingest-append", trace=trace)
+    assert res["correct"], res["checks"]
+    assert list(res)[-1] == "checks"
+    # 200 queries and 8 batches of 8 rows, each acked, applied and read back
+    assert res["attempted"] == 208 and res["failed"] == 0
+    for name in ("lost_acked", "unread_acked", "unsynced_acked"):
+        assert res["checks"][name] == {"value": 0, "limit": 0}
+    probes = res["checks"]["append_recall_loss"]
+    assert probes["value"] <= probes["limit"]
+    if trace:
+        assert INGEST_METRICS <= set(res["metrics"])
+        m = res["metrics"]
+        # each refresh uploads at least the padded f32 slab: 2^11 rows of 16
+        assert m["snapshot.upload_mb_per_refresh"]["value"] >= 2048 * 16 * 4 / 1e6
+    else:
+        assert set(res["metrics"]) == {"ingest_visible_p95_ms", "query_p95_ms",
+                                       "query_qps", "recall_at_10", "setup_s"}
+        assert res["metrics"]["ingest_visible_p95_ms"]["value"] > 0
+    assert not (layout.cache / "run").exists()
